@@ -1083,4 +1083,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main())

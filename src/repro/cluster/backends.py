@@ -40,6 +40,7 @@ from repro.core.state import ClusterState, ShardedState, SweepState
 from repro.core.streaming import dense_update, oracle_init, oracle_update, scan_update
 from repro.cluster.registry import BackendResult, register_backend
 from repro.core.wavefront import wavefront_update_megabatch
+from repro.kernels import interpret_mode
 from repro.kernels.edge_stream.ops import (
     pallas_decode_update_megabatch,
     pallas_fleet_update,
@@ -112,7 +113,6 @@ def _pallas_megabatch(edges, config, state) -> BackendResult:
         jnp.asarray(edges),
         int(config.v_max),
         chunk=config.chunk,
-        interpret=config.interpret,
     )
     return BackendResult(state=state, labels=state.c, info={})
 
@@ -122,12 +122,13 @@ def _pallas_wavefront(plan, config, state) -> BackendResult:
     node-disjoint waves with a runtime community-collision fallback, labels
     bit-identical to :func:`_pallas_megabatch` over the same stream.
 
-    In interpret mode the Pallas kernel would trace every wave through the
-    emulator, so we dispatch the pure-JAX reference apply instead — same
-    wave math (``repro.core.wavefront``), real vector units; the kernel
-    launch path is reserved for ``interpret=False`` hardware runs (and is
-    pinned against the reference by the wavefront test suite)."""
-    if config.interpret:
+    Off a TPU the Pallas kernel would trace every wave through the
+    interpreter, so the pure-JAX reference apply runs instead — same wave
+    math (``repro.core.wavefront``); the kernel itself is pinned against
+    the reference by the wavefront test suite.  On a TPU the kernel is
+    compiled, and Mosaic refuses it (``kernels/edge_stream/kernel.py``):
+    this configuration raises the compiler's error there."""
+    if interpret_mode():
         state, stats = wavefront_update_megabatch(
             state.to_device(),
             jnp.asarray(plan.waves),
@@ -143,7 +144,6 @@ def _pallas_wavefront(plan, config, state) -> BackendResult:
             jnp.asarray(plan.meta),
             int(config.v_max),
             chunk=config.chunk,
-            interpret=False,
         )
     return BackendResult(
         state=state, labels=state.c, info={"wavefront_stats": stats}
@@ -153,34 +153,28 @@ def _pallas_wavefront(plan, config, state) -> BackendResult:
 def _pallas_decode(cmega, config, state) -> BackendResult:
     """Device-resident compressed ingest (DESIGN.md §14): one fused
     decode→update dispatch per :class:`~repro.graph.pipeline
-    .CompressedMegaBatch` — on hardware the DVE3 lanes never leave the
-    chip (``kernel.edge_stream_decode_update_kernel`` unpacks descriptor
-    ``t+1``'s byte span while ``t``'s decoded window runs the per-edge
-    loop); in interpret mode the pure-JAX reference decode composes with
-    the megabatch kernel under the same jit.  Labels bit-identical to
-    host-decoding the same rows through :func:`_pallas_megabatch`."""
+    .CompressedMegaBatch` (``kernel.edge_stream_decode_update_kernel``
+    decodes each row from the payload bytes straight into the per-edge
+    update).  Labels bit-identical to host-decoding the same rows through
+    :func:`_pallas_megabatch`."""
     state = pallas_decode_update_megabatch(
         state.to_device(),
         jnp.asarray(cmega.payload),
         jnp.asarray(cmega.desc),
         int(config.v_max),
-        cmega.window,
-        cmega.out_rows,
-        chunk=config.chunk,
-        interpret=config.interpret,
     )
     return BackendResult(state=state, labels=state.c, info={})
 
 
 def _pallas_fleet(edges, config, state) -> BackendResult:
     """Tenant-major fleet kernel: one launch ingests the whole (T, B, 2)
-    slab, per-tenant state tiles pipelined HBM→VMEM→HBM (DESIGN.md §13);
+    slab, each tenant's state DMA'd HBM→VMEM→HBM (DESIGN.md §13);
     every tenant row bit-exact with the sequential single-stream tiers."""
     state = pallas_fleet_update(
         state.to_device(),
         jnp.asarray(edges),
         int(config.v_max),
-        interpret=config.interpret,
+        chunk=config.chunk,
     )
     return BackendResult(state=state, labels=None, info={})
 
@@ -202,7 +196,6 @@ def _pallas(edges, config, state, mesh=None) -> BackendResult:
         jnp.asarray(edges),
         int(config.v_max),
         chunk=config.chunk,
-        interpret=config.interpret,
     )
     return BackendResult(state=state, labels=state.c, info={})
 

@@ -126,8 +126,6 @@ class ClusterConfig:
         :class:`~repro.cluster.fleet.FleetClusterer` (single-stream entry
         points ignore it); requires a backend with a fleet path
         (``chunked`` / ``scan`` / ``pallas``).
-      interpret: run Pallas kernels in interpret mode (True on CPU; set
-        False on real TPUs).
       autosave_every: checkpoint the run from inside ``fit`` every this
         many ingested edges (rounded up to batch/megabatch boundaries —
         saves always land on exact resume cursors).  Requires
@@ -182,7 +180,6 @@ class ClusterConfig:
     wavefront_gap: Optional[int] = None
     tenants: Optional[int] = None
     device_decode: bool = False
-    interpret: bool = True
     autosave_every: Optional[int] = None
     autosave_dir: Optional[str] = None
     on_corrupt: str = "raise"
@@ -328,6 +325,9 @@ class ClusterConfig:
     @classmethod
     def from_json(cls, text: str) -> "ClusterConfig":
         raw = json.loads(text)
+        # configs saved before Pallas interpret mode was derived from the
+        # platform carry an ``interpret`` field; the platform decides now
+        raw.pop("interpret", None)
         if raw.get("v_maxes") is not None:
             raw["v_maxes"] = tuple(raw["v_maxes"])
         return cls(**raw)

@@ -4,12 +4,8 @@ The compressed ingest path (:meth:`BatchPipeline.compressed_megabatches`)
 ships DVE3 payload bytes plus a descriptor table instead of decoded edges;
 this module is the *specification* of what decoding that slab means:
 
-* every Pallas decode kernel is pinned bit-for-bit against
-  :func:`decode_megabatch` by the device-decode test suite and the CI
-  interpret leg;
-* in interpret mode the backends dispatch this implementation directly
-  (tracing a byte-unpack loop through the Pallas emulator would be
-  pointless — the reference *is* the same math on the same vector units);
+* the device-decode test suite pins it to the host-staged slab, and the
+  fused Pallas decode→update kernel to host decode + the sequential kernel;
 * :func:`chunked_decode_update_megabatch` fuses decode + the Jacobi
   megabatch update under one jit so the chunked tier keeps its
   one-dispatch-per-megabatch contract with ``device_decode`` on.
@@ -49,7 +45,7 @@ from repro.graph.pipeline import (
 )
 
 
-def _zigzag32(z):
+def zigzag_decode32(z):
     """Inverse zigzag on int32 (exact: fixed lanes are capped below 2**31)."""
     return (z >> 1) ^ -(z & 1)
 
@@ -108,9 +104,9 @@ def decode_megabatch(payload, desc, window: int, out_rows: int):
             w[:, None] == 1, v1, jnp.where(w[:, None] == 2, v2, v4)
         )
 
-    di = _zigzag32(fixed_col(off_i, w_i))
+    di = zigzag_decode32(fixed_col(off_i, w_i))
     fixed_i = base[:, None] + jnp.cumsum(di, axis=1, dtype=jnp.int32)
-    fixed_j = fixed_i + _zigzag32(fixed_col(off_j, w_j))
+    fixed_j = fixed_i + zigzag_decode32(fixed_col(off_j, w_j))
 
     # DESC_RAW: (n, 2) little-endian int32 pairs at off_i — 8-byte stride
     k = jnp.arange(window, dtype=jnp.int32)

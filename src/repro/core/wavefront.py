@@ -1,7 +1,7 @@
 """Device-side wavefront apply: vectorised waves, sequential fallback.
 
 Shared by the pure-JAX reference path (:func:`wavefront_update_megabatch`,
-used in interpret-mode runs and as the oracle for the kernel) and the
+used off a TPU and as the oracle for the kernel) and the
 Pallas wavefront kernel (``repro.kernels.edge_stream``), which imports
 :func:`wave_conflict` / :func:`wave_apply` so both paths apply *exactly*
 the same math (DESIGN.md §12).

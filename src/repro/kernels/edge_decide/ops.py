@@ -4,10 +4,12 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.edge_decide.kernel import build_call
 
 _LANES = 128
@@ -30,7 +32,7 @@ def edge_decide(
     live: jax.Array,
     v_max: int,
     block_rows: int = 8,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Decision stage over a batch of edges.  All inputs (B,) int32.
 
@@ -42,6 +44,6 @@ def edge_decide(
         _retile(x.astype(jnp.int32), rows)
         for x in (vci, vcj, di, dj, live.astype(jnp.int32))
     ]
-    call = build_call(rows, block_rows, v_max, interpret)
+    call = build_call(rows, block_rows, v_max, interpret_mode(interpret))
     action, amount = call(*args)
     return action.reshape(-1)[:b], amount.reshape(-1)[:b]

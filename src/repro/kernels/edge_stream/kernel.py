@@ -1,31 +1,46 @@
-"""Pallas TPU kernel: Algorithm 1 with the full (d, c, v) state in VMEM.
+"""Pallas TPU kernels: Algorithm 1 with the full (d, c, v) state in VMEM.
 
-TPU adaptation of the paper's CPU pointer-chasing loop (DESIGN.md §3):
-the state is exactly ``3n`` int32 — for n ≤ ~1.3M nodes that is ≤ 16 MB and
-fits VMEM, so every per-edge load/store hits VMEM (~ns latency) instead of
-HBM.  Two entry points share the same per-edge update:
+TPU adaptation of the paper's CPU pointer-chasing loop (DESIGN.md §3): the
+state is exactly ``3n`` int32 and stays in VMEM for a whole dispatch, so
+every per-edge load/store hits VMEM instead of HBM.  Semantics are bit-exact
+with ``core.streaming.cluster_stream_dense``: one sequential ``fori_loop``
+per edge chunk preserves the paper's strict stream order.
 
-* **Grid-pipelined** (:func:`build_call`): the edge stream is the *grid* —
-  chunk ``t`` is DMA'd HBM→VMEM by the Pallas pipeline while chunk ``t-1``
-  is being processed; the (d, c, v) output blocks have a constant index
-  map, so they stay resident in VMEM across all grid steps and are written
-  back to HBM once at the end.  One ``pallas_call`` per ingest batch.
-* **Megabatch, explicit double-buffered DMA** (:func:`build_megabatch_call`):
-  the whole ``(n_chunks, chunk, 2)`` megabatch stays in HBM
-  (``memory_space=ANY``) and the kernel drives its own edge DMA — two VMEM
-  chunk slots with manual ``make_async_copy``s, chunk ``t+1`` streaming in
-  while chunk ``t``'s sequential ``fori_loop`` runs, the state resident in
-  VMEM across the *entire* megabatch.  One ``pallas_call`` per ``K`` staged
-  pipeline batches (DESIGN.md §10 device pipelining).
+What Mosaic (the TPU kernel compiler in libtpu 0.0.34, JAX 0.9.0) accepts
+on a v5e, and therefore the layout of :func:`edge_stream_kernel`:
 
-Semantics are bit-exact with ``core.streaming.cluster_stream_dense`` — the
-sequential `fori_loop` inside the kernel preserves the paper's strict stream
-order (unlike the Jacobi tier), whichever entry point dispatches it.
+* **No scalar touches VMEM.**  Mosaic refuses scalar loads and stores on
+  VMEM refs ("Cannot store scalars to VMEM").  Each state array is held as
+  ``(rows, 128)`` int32 tiles, ``rows = 8 * ceil(n / 1024)`` (the (8, 128)
+  tiling rule), and node ``i`` lives at ``(i >> 7, i & 127)``.  A read is
+  a one-row load plus a lane-masked sum; a write is a lane-masked
+  read-modify-write of that row (:func:`_get`, :func:`_add`, :func:`_set`).
+* **Edge ids live in SMEM.**  They are used as scalars (row addresses), so
+  edge chunks are DMA'd HBM -> SMEM into two slots, chunk ``t+1`` streaming
+  in while chunk ``t``'s loop runs.  A chunk is laid out ``(2 * chunk /
+  128, 128)`` with ``(i, j)`` pairs interleaved, so the DMA source slice is
+  a whole leading-axis index (Mosaic refuses slices that split a tile).
+* **The VMEM limit is explicit.**  The state is DMA'd from HBM into VMEM
+  scratch once per dispatch and back once at the end (the HBM buffers are
+  aliased in place), taking ``12 * 128 * rows`` bytes.  A v5e has 128 MiB
+  of VMEM and the kernel asks for all of it: the compiler accepts
+  n = 11,100,000 and refuses n = 11,300,000 ("Ran out of memory in memory
+  space vmem"), so :data:`MAX_NODES` covers com-LiveJournal (n = 3,997,962)
+  but not com-Friendster.
 
-Layout note for real hardware: the 1-D state arrays would be lane-padded to
-(⌈n/128⌉, 128) tiles; scalar load/store then addresses (idx // 128, idx % 128).
-We keep the logical 1-D layout here (validated in interpret mode) and treat
-the retile as a mechanical lowering detail.
+One kernel serves every bit-exact edge-slab dispatch: the grid runs over
+independent streams, so a per-batch call and a ``(K, B, 2)`` megabatch are
+one stream (``T = 1``) and the tenant-major fleet is ``T`` streams, each
+with its own state rows.  The fused DVE3 decode→update kernel keeps the
+same state layout and decodes each edge from payload words DMA'd into SMEM
+with scalar shifts and masks (Mosaic has no lowering for the vector
+``dynamic_slice`` byte unpacking a lane-parallel decode would need).
+
+The wavefront kernel at the end of this module still keeps a flat 1-D
+state and vector gathers over it, which Mosaic refuses ("Only 2D gather is
+supported"); it runs in interpret mode off a TPU, and on a TPU the
+configuration that reaches it raises the compiler's error (ROADMAP reach
+item 1).
 """
 
 from __future__ import annotations
@@ -37,6 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.decode import zigzag_decode32
 from repro.core.wavefront import wave_apply, wave_conflict, wave_live
 from repro.graph.pipeline import (
     D_BASE,
@@ -44,7 +60,6 @@ from repro.graph.pipeline import (
     D_NROWS,
     D_OFF_I,
     D_OFF_J,
-    D_ROW,
     D_W_I,
     D_W_J,
     DESC_COLS,
@@ -53,10 +68,357 @@ from repro.graph.pipeline import (
     PAD,
 )
 
+LANES = 128
+SUBLANES = 8
+N_EDGE_SLOTS = 2  # double buffering: one slot streams in, one is consumed
+VMEM_BYTES = 128 << 20  # v5e VMEM capacity, requested as the scoped limit
+MAX_NODES = 11_100_000  # largest n the v5e compiler accepts (see above)
+GROUP_BYTES = 4 * SUBLANES * LANES  # payload bytes per (8, 128) int32 group
+DECODE_ROWS = 2048  # stream rows decoded per pair of span DMAs
+# groups one span DMA covers: 8 bytes per row at most, from any start byte
+SPAN_GROUPS = -(-8 * DECODE_ROWS // GROUP_BYTES) + 1
 
-def _apply_edge(i_raw, j_raw, d_ref, c_ref, v_ref, *, v_max: int):
-    """One Algorithm-1 step against the VMEM-resident (d, c, v) refs —
-    shared by the grid-pipelined and manual-DMA kernels."""
+
+def state_rows(n: int) -> int:
+    """Rows of the ``(rows, 128)`` tile layout holding ``n`` node slots."""
+    return -(-n // (SUBLANES * LANES)) * SUBLANES
+
+
+def edge_chunk(chunk: int) -> int:
+    """DMA chunk in edges: ``chunk`` rounded up to fill whole 128-lane rows
+    of interleaved ``(i, j)`` pairs."""
+    return -(-chunk // (LANES // 2)) * (LANES // 2)
+
+
+def _lane_mask(idx):
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    return lanes == (idx & (LANES - 1))
+
+
+def _get(ref, idx):
+    """``ref[idx]`` of a tiled state array, without a scalar VMEM load."""
+    row = ref[pl.ds(idx >> 7, 1), :]
+    return jnp.sum(jnp.where(_lane_mask(idx), row, 0))
+
+
+def _add(ref, idx, delta):
+    """``ref[idx] += delta`` as a lane-masked read-modify-write of one row."""
+    rows = pl.ds(idx >> 7, 1)
+    ref[rows, :] = ref[rows, :] + jnp.where(_lane_mask(idx), delta, 0)
+
+
+def _set(ref, idx, value):
+    """``ref[idx] = value`` as a lane-masked read-modify-write of one row."""
+    rows = pl.ds(idx >> 7, 1)
+    ref[rows, :] = jnp.where(_lane_mask(idx), value, ref[rows, :])
+
+
+def _apply_edge(i, j, d_ref, c_ref, v_ref, *, v_max: int):
+    """One Algorithm-1 step against the VMEM-resident tiled (d, c, v)."""
+
+    @pl.when((i != PAD) & (j != PAD) & (i != j))
+    def _update():
+        _add(d_ref, i, 1)
+        _add(d_ref, j, 1)
+        di = _get(d_ref, i)
+        dj = _get(d_ref, j)
+        ci = _get(c_ref, i)
+        cj = _get(c_ref, j)
+        # Sequential +1 per endpoint community; reload so ci == cj sees +2.
+        _add(v_ref, ci, 1)
+        _add(v_ref, cj, 1)
+        vci = _get(v_ref, ci)
+        vcj = _get(v_ref, cj)
+        ok = (vci <= v_max) & (vcj <= v_max)
+
+        @pl.when(ok & (vci <= vcj))
+        def _move_i():  # i joins the community of j
+            _add(v_ref, cj, di)
+            _add(v_ref, ci, -di)
+            _set(c_ref, i, cj)
+
+        @pl.when(ok & (vci > vcj))
+        def _move_j():  # j joins the community of i
+            _add(v_ref, ci, dj)
+            _add(v_ref, cj, -dj)
+            _set(c_ref, j, ci)
+
+
+def _copy_state(t, state, sems, *, load: bool):
+    """DMA stream ``t``'s (d, c, v) from HBM into the VMEM scratch
+    (``load``) or back; ``state`` holds ``(hbm_in, vmem, hbm_out)``."""
+    copies = [
+        pltpu.make_async_copy(src.at[t], vmem, sems.at[s])
+        if load
+        else pltpu.make_async_copy(vmem, dst.at[t], sems.at[s])
+        for s, (src, vmem, dst) in enumerate(state)
+    ]
+    for cp in copies:
+        cp.start()
+    for cp in copies:
+        cp.wait()
+
+
+def edge_stream_kernel(
+    edges_hbm,
+    d_in,
+    c_in,
+    v_in,
+    d_out,
+    c_out,
+    v_out,
+    d_ref,
+    c_ref,
+    v_ref,
+    slots_ref,
+    edge_sems,
+    state_sems,
+    *,
+    v_max: int,
+    chunk: int,
+    n_chunks: int,
+):
+    """Grid step ``t`` ingests stream ``t``'s edge chunks into its state.
+
+    ``edges_hbm`` is ``(T, n_chunks, 2 * chunk / 128, 128)`` in HBM; the
+    state arrays are ``(T, rows, 128)`` in HBM, aliased input to output.
+    Stream ``t``'s state is DMA'd into the VMEM scratch once, chunk ``k+1``
+    of its edges streams into the free SMEM slot while chunk ``k``'s
+    sequential loop runs, and the state is DMA'd back once at the end.
+    Streams never share state, so the grid order is irrelevant and each
+    stream's result is bit-exact with a standalone sequential run; PAD rows
+    (a ragged tail, an idle tenant) are no-ops.
+    """
+    t = pl.program_id(0)
+    state = ((d_in, d_ref, d_out), (c_in, c_ref, c_out), (v_in, v_ref, v_out))
+
+    def edge_dma(k):
+        slot = jax.lax.rem(k, N_EDGE_SLOTS)
+        return pltpu.make_async_copy(
+            edges_hbm.at[t, k], slots_ref.at[slot], edge_sems.at[slot]
+        )
+
+    edge_dma(jnp.int32(0)).start()
+    _copy_state(t, state, state_sems, load=True)
+
+    def chunk_body(k, carry):
+        @pl.when(k + 1 < n_chunks)
+        def _prefetch_next():
+            edge_dma(k + 1).start()
+
+        edge_dma(k).wait()
+        slot = jax.lax.rem(k, N_EDGE_SLOTS)
+
+        def body(e, cy):
+            p = 2 * e  # flat position of the pair in the (rows, 128) slot
+            _apply_edge(
+                slots_ref[slot, p >> 7, p & (LANES - 1)],
+                slots_ref[slot, p >> 7, (p + 1) & (LANES - 1)],
+                d_ref,
+                c_ref,
+                v_ref,
+                v_max=v_max,
+            )
+            return cy
+
+        jax.lax.fori_loop(0, chunk, body, None)
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, chunk_body, None)
+    _copy_state(t, state, state_sems, load=False)
+
+
+def build_call(
+    tenants: int,
+    n: int,
+    chunk: int,
+    n_chunks: int,
+    v_max: int,
+    interpret: bool,
+):
+    """One dispatch over ``tenants`` independent streams: edges
+    ``(T, n_chunks, 2 * chunk / 128, 128)`` and states ``(T, rows, 128)``,
+    both left in HBM and DMA'd by the kernel (``chunk`` must be an
+    :func:`edge_chunk`)."""
+    rows = state_rows(n)
+    kernel = functools.partial(
+        edge_stream_kernel, v_max=v_max, chunk=chunk, n_chunks=n_chunks
+    )
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    state = jax.ShapeDtypeStruct((tenants, rows, LANES), jnp.int32)
+    return pl.pallas_call(
+        kernel,
+        grid=(tenants,),
+        in_specs=[hbm] * 4,
+        out_specs=[hbm] * 3,
+        out_shape=[state] * 3,  # d, c, v
+        scratch_shapes=[
+            pltpu.VMEM((rows, LANES), jnp.int32),
+            pltpu.VMEM((rows, LANES), jnp.int32),
+            pltpu.VMEM((rows, LANES), jnp.int32),
+            pltpu.SMEM((N_EDGE_SLOTS, 2 * chunk // LANES, LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((N_EDGE_SLOTS,)),
+            pltpu.SemaphoreType.DMA((3,)),
+        ],
+        input_output_aliases={1: 0, 2: 1, 3: 2},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES),
+        interpret=interpret,
+    )
+
+
+def payload_groups(payload_bytes: int) -> int:
+    """``(8, 128)``-word groups of the padded payload: every span DMA of
+    :func:`edge_stream_decode_update_kernel` starts inside the payload and
+    stays in bounds."""
+    return -(-payload_bytes // GROUP_BYTES) + SPAN_GROUPS
+
+
+def _span_value(span_ref, pos, width):
+    """Little-endian ``width``-byte (1, 2 or 4) value at byte ``pos`` of a
+    ``(SPAN_GROUPS, 8, 128)`` int32 SMEM span; segments are 8-byte aligned,
+    so a value never straddles two words."""
+    q = pos >> 2
+    word = span_ref[q >> 10, (q >> 7) & (SUBLANES - 1), q & (LANES - 1)]
+    mask = jnp.where(width == 1, 0xFF, jnp.where(width == 2, 0xFFFF, -1))
+    return (word >> ((pos & 3) * 8)) & mask
+
+
+def edge_stream_decode_update_kernel(
+    desc_ref,
+    payload_hbm,
+    d_in,
+    c_in,
+    v_in,
+    d_out,
+    c_out,
+    v_out,
+    live_out,
+    d_ref,
+    c_ref,
+    v_ref,
+    spans_ref,
+    span_sems,
+    state_sems,
+    *,
+    v_max: int,
+    d_max: int,
+):
+    """Fused decode→update: compressed bytes in, clustered state out.
+
+    One launch per compressed megabatch.  ``desc_ref`` is the flattened
+    descriptor table in SMEM; ``payload_hbm`` the payload as ``(G, 8, 128)``
+    int32 words left in HBM.  Live descriptors tile the stream in order
+    (``CompressedMegaBatch.validate``), so walking them in order and
+    decoding each one's rows with scalar arithmetic replays exactly the
+    stream the host-decode path stages.  Each descriptor is two byte
+    columns — DVE3 zigzag ``i`` deltas and ``j - i`` offsets, or the ``i``
+    and ``j`` words of raw int32 pairs — DMA'd into SMEM
+    :data:`DECODE_ROWS` rows at a time; row ``e`` is decoded and applied at
+    once with :func:`_apply_edge`, so the decoded edges never touch HBM.
+    The (d, c, v) tiles are DMA'd into VMEM once and back once, and
+    ``live_out[0]`` returns the live-edge count.
+    """
+    state = ((d_in, d_ref, d_out), (c_in, c_ref, c_out), (v_in, v_ref, v_out))
+    _copy_state(0, state, state_sems, load=True)
+
+    def desc_body(t, live):
+        row = t * DESC_COLS
+        kind = desc_ref[row + D_KIND]
+        raw = kind == DESC_RAW
+        nrows = jnp.where(kind == DESC_EMPTY, 0, desc_ref[row + D_NROWS])
+        off_i = desc_ref[row + D_OFF_I]
+        w_i = desc_ref[row + D_W_I]
+        w_j = desc_ref[row + D_W_J]
+        starts = (off_i, jnp.where(raw, off_i + 4, desc_ref[row + D_OFF_J]))
+        strides = (jnp.where(raw, 8, w_i), jnp.where(raw, 8, w_j))
+        widths = (jnp.where(raw, 4, w_i), jnp.where(raw, 4, w_j))
+
+        def sub_body(s, carry):
+            e0 = s * DECODE_ROWS
+            firsts, copies = [], []
+            for col in range(2):
+                first = starts[col] + e0 * strides[col]
+                group = first // GROUP_BYTES
+                copies.append(
+                    pltpu.make_async_copy(
+                        payload_hbm.at[pl.ds(group, SPAN_GROUPS)],
+                        spans_ref.at[col],
+                        span_sems.at[col],
+                    )
+                )
+                firsts.append(first - group * GROUP_BYTES)
+            for cp in copies:
+                cp.start()
+            for cp in copies:
+                cp.wait()
+
+            def edge_body(e, cy):
+                acc, live = cy
+                a, b = (
+                    _span_value(spans_ref.at[col],
+                                firsts[col] + e * strides[col], widths[col])
+                    for col in range(2)
+                )
+                i = jnp.where(raw, a, acc + zigzag_decode32(a))
+                j = jnp.where(raw, b, i + zigzag_decode32(b))
+                _apply_edge(i, j, d_ref, c_ref, v_ref, v_max=v_max)
+                ok = (i != PAD) & (j != PAD) & (i != j)
+                return i, live + ok.astype(jnp.int32)
+
+            rows = jnp.minimum(DECODE_ROWS, nrows - e0)
+            return jax.lax.fori_loop(0, rows, edge_body, carry)
+
+        n_sub = (nrows + DECODE_ROWS - 1) // DECODE_ROWS
+        base = desc_ref[row + D_BASE]
+        _, live = jax.lax.fori_loop(0, n_sub, sub_body, (base, live))
+        return live
+
+    live_out[0] = jax.lax.fori_loop(0, d_max, desc_body, jnp.int32(0))
+    _copy_state(0, state, state_sems, load=False)
+
+
+def build_decode_update_call(
+    n: int, groups: int, d_max: int, v_max: int, interpret: bool
+):
+    """One fused dispatch over a compressed megabatch: the flattened
+    ``(d_max * DESC_COLS,)`` descriptor table in SMEM, the ``(groups, 8,
+    128)`` payload words and the ``(1, rows, 128)`` states left in HBM
+    (states aliased in place); returns d, c, v and a ``(1,)`` live-edge
+    count."""
+    rows = state_rows(n)
+    kernel = functools.partial(
+        edge_stream_decode_update_kernel, v_max=v_max, d_max=d_max
+    )
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    state = jax.ShapeDtypeStruct((1, rows, LANES), jnp.int32)
+    return pl.pallas_call(
+        kernel,
+        grid=(1,),
+        in_specs=[smem] + [hbm] * 4,
+        out_specs=[hbm] * 3 + [smem],
+        out_shape=[state] * 3 + [jax.ShapeDtypeStruct((1,), jnp.int32)],
+        scratch_shapes=[
+            pltpu.VMEM((rows, LANES), jnp.int32),
+            pltpu.VMEM((rows, LANES), jnp.int32),
+            pltpu.VMEM((rows, LANES), jnp.int32),
+            pltpu.SMEM((2, SPAN_GROUPS, SUBLANES, LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((3,)),
+        ],
+        input_output_aliases={2: 0, 3: 1, 4: 2},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES),
+        interpret=interpret,
+    )
+
+
+# ---------------------------------------------------------------------------
+# Flat-layout kernel: interpret mode only (Mosaic refuses it, see above)
+# ---------------------------------------------------------------------------
+
+def _apply_edge_flat(i_raw, j_raw, d_ref, c_ref, v_ref, *, v_max: int):
+    """One Algorithm-1 step against flat 1-D (d, c, v) refs, by scalar
+    loads and stores — the form Mosaic refuses on VMEM."""
     live = (i_raw != PAD) & (j_raw != PAD) & (i_raw != j_raw)
     i = jnp.maximum(i_raw, 0)
     j = jnp.maximum(j_raw, 0)
@@ -70,151 +432,24 @@ def _apply_edge(i_raw, j_raw, d_ref, c_ref, v_ref, *, v_max: int):
 
         ci = c_ref[i]
         cj = c_ref[j]
-        # Sequential +1 per endpoint community; reload so ci == cj sees +2.
         v_ref[ci] = v_ref[ci] + 1
         v_ref[cj] = v_ref[cj] + 1
         vci = v_ref[ci]
         vcj = v_ref[cj]
 
         ok = (vci <= v_max) & (vcj <= v_max)
-        i_joins = ok & (vci <= vcj)
-        j_joins = ok & (vci > vcj)
 
-        @pl.when(i_joins)
-        def _move_i():  # i joins the community of j
+        @pl.when(ok & (vci <= vcj))
+        def _move_i():
             v_ref[cj] = v_ref[cj] + di
             v_ref[ci] = v_ref[ci] - di
             c_ref[i] = cj
 
-        @pl.when(j_joins)
-        def _move_j():  # j joins the community of i
+        @pl.when(ok & (vci > vcj))
+        def _move_j():
             v_ref[ci] = v_ref[ci] + dj
             v_ref[cj] = v_ref[cj] - dj
             c_ref[j] = ci
-
-
-def edge_stream_kernel(
-    edges_ref, d0_ref, c0_ref, v0_ref, d_ref, c_ref, v_ref, *, v_max: int, n: int
-):
-    """Process one edge chunk; (d, c, v) persist in VMEM across grid steps.
-
-    ``(d0, c0, v0)`` seed the state at grid step 0 — a fresh run passes
-    zeros/iota, a resumed run (``repro.cluster.StreamClusterer``) passes the
-    carried :class:`ClusterState` arrays.
-    """
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        d_ref[...] = d0_ref[...]
-        c_ref[...] = c0_ref[...]
-        v_ref[...] = v0_ref[...]
-
-    chunk = edges_ref.shape[0]
-
-    def body(e, carry):
-        _apply_edge(
-            edges_ref[e, 0], edges_ref[e, 1], d_ref, c_ref, v_ref, v_max=v_max
-        )
-        return carry
-
-    jax.lax.fori_loop(0, chunk, body, None)
-
-
-def build_call(n: int, chunk: int, n_chunks: int, v_max: int, interpret: bool):
-    kernel = functools.partial(edge_stream_kernel, v_max=v_max, n=n)
-    state_spec = pl.BlockSpec((n,), lambda t: (0,))
-    return pl.pallas_call(
-        kernel,
-        grid=(n_chunks,),
-        in_specs=[
-            pl.BlockSpec((chunk, 2), lambda t: (t, 0)),
-            state_spec,
-            state_spec,
-            state_spec,
-        ],
-        out_specs=[state_spec, state_spec, state_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),  # d
-            jax.ShapeDtypeStruct((n,), jnp.int32),  # c
-            jax.ShapeDtypeStruct((n,), jnp.int32),  # v
-        ],
-        interpret=interpret,
-    )
-
-
-N_EDGE_SLOTS = 2  # double buffering: one slot streams in, one is consumed
-
-
-def edge_stream_megabatch_kernel(
-    edges_hbm_ref,
-    d0_ref,
-    c0_ref,
-    v0_ref,
-    d_ref,
-    c_ref,
-    v_ref,
-    *,
-    v_max: int,
-    n: int,
-    chunk: int,
-    n_chunks: int,
-):
-    """Whole-megabatch kernel with explicit double-buffered edge DMA.
-
-    ``edges_hbm_ref`` is the full ``(n_chunks, chunk, 2)`` megabatch, left
-    in HBM (``memory_space=ANY``).  The kernel owns the edge movement: two
-    ``(chunk, 2)`` VMEM slots, chunk ``t+1``'s async copy started *before*
-    chunk ``t``'s sequential edge loop runs, so the DMA engine streams edges
-    while the scalar loop updates the VMEM-resident (d, c, v).  One kernel
-    launch ingests the entire megabatch — the state never round-trips to
-    HBM between the K staged batches.
-    """
-    d_ref[...] = d0_ref[...]
-    c_ref[...] = c0_ref[...]
-    v_ref[...] = v0_ref[...]
-
-    def scoped(slots_ref, sems_ref):
-        def edge_dma(t):
-            slot = jax.lax.rem(t, N_EDGE_SLOTS)
-            return pltpu.make_async_copy(
-                edges_hbm_ref.at[t], slots_ref.at[slot], sems_ref.at[slot]
-            )
-
-        # Warm-up: chunk 0 starts streaming before the loop.
-        edge_dma(jnp.int32(0)).start()
-
-        def chunk_body(t, carry):
-            # Kick off chunk t+1 while chunk t is (still) in flight /
-            # being consumed — the double buffer's other slot is free.
-            @pl.when(t + 1 < n_chunks)
-            def _prefetch_next():
-                edge_dma(t + 1).start()
-
-            edge_dma(t).wait()
-            slot = jax.lax.rem(t, N_EDGE_SLOTS)
-
-            def body(e, c):
-                _apply_edge(
-                    slots_ref[slot, e, 0],
-                    slots_ref[slot, e, 1],
-                    d_ref,
-                    c_ref,
-                    v_ref,
-                    v_max=v_max,
-                )
-                return c
-
-            jax.lax.fori_loop(0, chunk, body, None)
-            return carry
-
-        jax.lax.fori_loop(0, n_chunks, chunk_body, None)
-
-    pl.run_scoped(
-        scoped,
-        pltpu.VMEM((N_EDGE_SLOTS, chunk, 2), jnp.int32),
-        pltpu.SemaphoreType.DMA((N_EDGE_SLOTS,)),
-    )
 
 
 def edge_stream_wavefront_kernel(
@@ -240,13 +475,13 @@ def edge_stream_wavefront_kernel(
 
     ``waves_hbm_ref`` holds the planner's ``(n_waves, width, 2)`` layout in
     HBM; waves are double-buffer DMA'd into VMEM like the sequential
-    megabatch kernel's chunks.  Each wave is applied as gathered vector
+    kernel's edge chunks.  Each wave is applied as gathered vector
     loads / scattered stores against the VMEM-resident (d, c, v) via the
     shared :func:`repro.core.wavefront.wave_apply` — after a runtime
     community-disjointness check (:func:`wave_conflict`) against the live
     state; colliding waves fall back to the sequential per-edge
     ``fori_loop``, so labels stay bit-identical to
-    :func:`edge_stream_megabatch_kernel` for any plan.  The uncovered
+    :func:`edge_stream_kernel` for any plan.  The uncovered
     stream suffix (``meta_ref[1]`` rows, chunked in ``left_hbm_ref``) is
     drained sequentially at the end.  ``meta_ref[0]`` bounds the wave loop
     so trailing all-PAD waves cost nothing.  ``stats_ref`` returns
@@ -277,9 +512,7 @@ def edge_stream_wavefront_kernel(
 
             wave_dma(t).wait()
             slot = jax.lax.rem(t, N_EDGE_SLOTS)
-            wave = pl.load(
-                slots_ref, (pl.dslice(slot, 1), slice(None), slice(None))
-            )[0]
+            wave = slots_ref[slot]
             i_raw = wave[:, 0]
             j_raw = wave[:, 1]
             c_now = c_ref[...]
@@ -299,7 +532,7 @@ def edge_stream_wavefront_kernel(
             @pl.when(conflict)
             def _sequential():
                 def body(e, cy):
-                    _apply_edge(
+                    _apply_edge_flat(
                         wave[e, 0], wave[e, 1], d_ref, c_ref, v_ref,
                         v_max=v_max,
                     )
@@ -334,7 +567,7 @@ def edge_stream_wavefront_kernel(
             cp.wait()
 
             def body(e, cy):
-                _apply_edge(
+                _apply_edge_flat(
                     slot_ref[e, 0], slot_ref[e, 1], d_ref, c_ref, v_ref,
                     v_max=v_max,
                 )
@@ -349,367 +582,6 @@ def edge_stream_wavefront_kernel(
         left_scoped,
         pltpu.VMEM((chunk, 2), jnp.int32),
         pltpu.SemaphoreType.DMA(()),
-    )
-
-
-def edge_stream_fleet_kernel(
-    edges_ref, d0_ref, c0_ref, v0_ref, d_ref, c_ref, v_ref, *, v_max: int,
-    batch: int,
-):
-    """Tenant-major fleet kernel: grid step ``t`` ingests tenant ``t``.
-
-    The fleet's ``(T, B, 2)`` staged slab and ``(T, n)`` state arrays live
-    in HBM; the Pallas pipeline DMAs tenant ``t``'s ``(1, B, 2)`` edge slab
-    and ``(1, n)`` d/c/v slabs into VMEM per grid step (tenant ``t+1``'s
-    tiles stream in while tenant ``t``'s sequential edge loop runs — same
-    double buffering the grid-pipelined single-stream kernel gets for
-    chunks).  Per-tenant semantics are the strict-stream-order
-    :func:`_apply_edge` loop, so row ``t`` is bit-exact with a standalone
-    sequential run of tenant ``t`` — tenants never share state, so the
-    grid order is irrelevant.  All-PAD slabs (idle tenants) are no-ops.
-    """
-    d_ref[...] = d0_ref[...]
-    c_ref[...] = c0_ref[...]
-    v_ref[...] = v0_ref[...]
-    # Squeeze the leading tenant-block axis so the shared per-edge update
-    # sees plain (n,) refs.
-    dr, cr, vr = d_ref.at[0], c_ref.at[0], v_ref.at[0]
-
-    def body(e, carry):
-        _apply_edge(
-            edges_ref[0, e, 0], edges_ref[0, e, 1], dr, cr, vr, v_max=v_max
-        )
-        return carry
-
-    jax.lax.fori_loop(0, batch, body, None)
-
-
-def build_fleet_call(
-    n: int, tenants: int, batch: int, v_max: int, interpret: bool
-):
-    """One fused dispatch over a ``(T, B, 2)`` fleet slab: the tenant axis
-    is the grid, per-tenant state tiles are DMA'd HBM→VMEM→HBM by the
-    Pallas pipeline (``3n`` ints per tenant — only one tenant's slabs are
-    VMEM-resident at a time, so fleet size is bounded by HBM, not VMEM)."""
-    kernel = functools.partial(
-        edge_stream_fleet_kernel, v_max=v_max, batch=batch
-    )
-    state_spec = pl.BlockSpec((1, n), lambda t: (t, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(tenants,),
-        in_specs=[
-            pl.BlockSpec((1, batch, 2), lambda t: (t, 0, 0)),
-            state_spec,
-            state_spec,
-            state_spec,
-        ],
-        out_specs=[state_spec, state_spec, state_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((tenants, n), jnp.int32),  # d
-            jax.ShapeDtypeStruct((tenants, n), jnp.int32),  # c
-            jax.ShapeDtypeStruct((tenants, n), jnp.int32),  # v
-        ],
-        interpret=interpret,
-    )
-
-
-def _decode_span(window: int) -> int:
-    """Bytes DMA'd per descriptor: the widest segment is a DESC_RAW window
-    (8 bytes/row) or a u4+u4 fixed pair (4 + 4 bytes/row plus one alignment
-    gap) — both bounded by ``8 * window + 8``.  The staging producer leaves
-    this much tail slack in the payload slab, so a fixed-size span read at
-    any live descriptor offset is always in bounds."""
-    return 8 * window + 8
-
-
-def _decode_window(desc_row, bytes_i32, *, window: int):
-    """Decode one descriptor's span into ``(window, 2)`` int32 edge rows.
-
-    ``desc_row`` is the (DESC_COLS,) descriptor; ``bytes_i32`` the span's
-    bytes as int32 values, with the descriptor's first data byte
-    (``off_i``) at position 0.  All candidate widths are unpacked with
-    reshape-and-combine lane math and selected by the descriptor's width
-    fields — no per-byte scalar loop.  Rows at/after ``n_rows`` (and the
-    whole window for DESC_EMPTY) come out PAD, so a consumer can treat
-    every window as exactly ``window`` stream rows.  Shared by the
-    standalone decode kernel and the fused decode→update kernel, and
-    pinned bit-for-bit against ``repro.core.decode.decode_megabatch``.
-    """
-    kind = desc_row[D_KIND]
-    nrows = desc_row[D_NROWS]
-    w_i = desc_row[D_W_I]
-    w_j = desc_row[D_W_J]
-    rel_j = desc_row[D_OFF_J] - desc_row[D_OFF_I]
-    base = desc_row[D_BASE]
-
-    def fixed_col(rel, w):
-        v1 = jax.lax.dynamic_slice(bytes_i32, (rel,), (window,))
-        p2 = jax.lax.dynamic_slice(bytes_i32, (rel,), (2 * window,)).reshape(
-            window, 2
-        )
-        v2 = p2[:, 0] | (p2[:, 1] << 8)
-        p4 = jax.lax.dynamic_slice(bytes_i32, (rel,), (4 * window,)).reshape(
-            window, 4
-        )
-        v4 = p4[:, 0] | (p4[:, 1] << 8) | (p4[:, 2] << 16) | (p4[:, 3] << 24)
-        return jnp.where(w == 1, v1, jnp.where(w == 2, v2, v4))
-
-    def unzig(z):
-        return (z >> 1) ^ -(z & 1)
-
-    di = unzig(fixed_col(jnp.int32(0), w_i))
-    fixed_i = base + jnp.cumsum(di, dtype=jnp.int32)
-    fixed_j = fixed_i + unzig(fixed_col(rel_j, w_j))
-
-    # DESC_RAW: little-endian int32 (i, j) pairs — 8 bytes per row
-    p8 = bytes_i32[: 8 * window].reshape(window, 8)
-    raw_i = p8[:, 0] | (p8[:, 1] << 8) | (p8[:, 2] << 16) | (p8[:, 3] << 24)
-    raw_j = p8[:, 4] | (p8[:, 5] << 8) | (p8[:, 6] << 16) | (p8[:, 7] << 24)
-
-    is_raw = kind == DESC_RAW
-    vals_i = jnp.where(is_raw, raw_i, fixed_i)
-    vals_j = jnp.where(is_raw, raw_j, fixed_j)
-    rows = jnp.stack([vals_i, vals_j], axis=1)
-    rowid = jax.lax.broadcasted_iota(jnp.int32, (window, 2), 0)
-    live = (rowid < nrows) & (kind != DESC_EMPTY)
-    return jnp.where(live, rows, PAD)
-
-
-def decode_megabatch_kernel(
-    desc_ref,
-    payload_hbm_ref,
-    out_hbm_ref,
-    *,
-    window: int,
-    d_max: int,
-    n_out_windows: int,
-):
-    """Standalone compressed-slab decode: payload bytes in, edge slab out.
-
-    The payload stays in HBM (``memory_space=ANY``); descriptor spans are
-    double-buffer DMA'd into two VMEM byte slots — descriptor ``t+1``'s
-    bytes stream in while ``t``'s lanes are unpacked — and each decoded
-    ``(window, 2)`` window is DMA'd back to the HBM output slab at its
-    destination row.  Windows are written in ascending ``dest_row`` order
-    and a window's PAD tail may be overwritten by the next segment's real
-    rows, which is exactly how the host-staged slab composes; a PAD
-    pre-pass covers rows no descriptor reaches (the ragged stream tail).
-    """
-    span = _decode_span(window)
-
-    def scoped(slots_ref, sems_ref, row_ref, out_sem):
-        # PAD pre-pass: the slab must read PAD wherever no live descriptor
-        # lands (trailing all-PAD batches of a ragged tail megabatch)
-        row_ref[...] = jnp.full((window, 2), PAD, jnp.int32)
-
-        def pad_body(t, carry):
-            cp = pltpu.make_async_copy(
-                row_ref,
-                out_hbm_ref.at[pl.ds(t * window, window), :],
-                out_sem,
-            )
-            cp.start()
-            cp.wait()
-            return carry
-
-        jax.lax.fori_loop(0, n_out_windows, pad_body, None)
-
-        def bytes_dma(t):
-            slot = jax.lax.rem(t, N_EDGE_SLOTS)
-            off = desc_ref[t, D_OFF_I]
-            return pltpu.make_async_copy(
-                payload_hbm_ref.at[pl.ds(off, span)],
-                slots_ref.at[slot],
-                sems_ref.at[slot],
-            )
-
-        bytes_dma(jnp.int32(0)).start()
-
-        def body(t, carry):
-            @pl.when(t + 1 < d_max)
-            def _prefetch_next():
-                bytes_dma(t + 1).start()
-
-            bytes_dma(t).wait()
-            slot = jax.lax.rem(t, N_EDGE_SLOTS)
-            desc_row = pl.load(
-                desc_ref, (pl.dslice(t, 1), slice(None))
-            )[0]
-            rows = _decode_window(
-                desc_row, slots_ref[slot].astype(jnp.int32), window=window
-            )
-
-            @pl.when(desc_row[D_KIND] != DESC_EMPTY)
-            def _write():
-                row_ref[...] = rows
-                cp = pltpu.make_async_copy(
-                    row_ref,
-                    out_hbm_ref.at[pl.ds(desc_row[D_ROW], window), :],
-                    out_sem,
-                )
-                cp.start()
-                cp.wait()
-
-            return carry
-
-        jax.lax.fori_loop(0, d_max, body, None)
-
-    pl.run_scoped(
-        scoped,
-        pltpu.VMEM((N_EDGE_SLOTS, _decode_span(window)), jnp.uint8),
-        pltpu.SemaphoreType.DMA((N_EDGE_SLOTS,)),
-        pltpu.VMEM((window, 2), jnp.int32),
-        pltpu.SemaphoreType.DMA(()),
-    )
-
-
-def edge_stream_decode_update_kernel(
-    desc_ref,
-    payload_hbm_ref,
-    d0_ref,
-    c0_ref,
-    v0_ref,
-    d_ref,
-    c_ref,
-    v_ref,
-    stats_ref,
-    *,
-    v_max: int,
-    window: int,
-    d_max: int,
-):
-    """Fused decode→update: compressed bytes in, clustered state out.
-
-    One launch per compressed megabatch: descriptor spans double-buffer
-    DMA from the HBM payload slab (descriptor ``t+1``'s bytes in flight
-    while ``t`` is decoded and applied — PR 5's DMA structure with byte
-    spans in place of decoded chunks), lanes unpack in VMEM via
-    :func:`_decode_window`, and the decoded window immediately runs the
-    strict-order sequential :func:`_apply_edge` loop against the
-    VMEM-resident (d, c, v).  Descriptors tile the stream in order and PAD
-    rows are no-ops, so labels are bit-exact with host decode + the plain
-    megabatch kernel over the same rows.  The decoded edges never touch
-    HBM.  ``stats_ref[0]`` returns the live-edge count (the host can't
-    cheaply know it without decoding).
-    """
-    d_ref[...] = d0_ref[...]
-    c_ref[...] = c0_ref[...]
-    v_ref[...] = v0_ref[...]
-    stats_ref[...] = jnp.zeros((1,), jnp.int32)
-    span = _decode_span(window)
-
-    def scoped(slots_ref, sems_ref):
-        def bytes_dma(t):
-            slot = jax.lax.rem(t, N_EDGE_SLOTS)
-            off = desc_ref[t, D_OFF_I]
-            return pltpu.make_async_copy(
-                payload_hbm_ref.at[pl.ds(off, span)],
-                slots_ref.at[slot],
-                sems_ref.at[slot],
-            )
-
-        bytes_dma(jnp.int32(0)).start()
-
-        def body(t, carry):
-            @pl.when(t + 1 < d_max)
-            def _prefetch_next():
-                bytes_dma(t + 1).start()
-
-            bytes_dma(t).wait()
-            slot = jax.lax.rem(t, N_EDGE_SLOTS)
-            desc_row = pl.load(
-                desc_ref, (pl.dslice(t, 1), slice(None))
-            )[0]
-            rows = _decode_window(
-                desc_row, slots_ref[slot].astype(jnp.int32), window=window
-            )
-            live = (
-                (rows[:, 0] != PAD)
-                & (rows[:, 1] != PAD)
-                & (rows[:, 0] != rows[:, 1])
-            )
-            stats_ref[0] = stats_ref[0] + jnp.sum(live.astype(jnp.int32))
-
-            @pl.when(desc_row[D_KIND] != DESC_EMPTY)
-            def _apply():
-                def edge_body(e, cy):
-                    _apply_edge(
-                        rows[e, 0], rows[e, 1], d_ref, c_ref, v_ref,
-                        v_max=v_max,
-                    )
-                    return cy
-
-                jax.lax.fori_loop(0, window, edge_body, None)
-
-            return carry
-
-        jax.lax.fori_loop(0, d_max, body, None)
-
-    pl.run_scoped(
-        scoped,
-        pltpu.VMEM((N_EDGE_SLOTS, _decode_span(window)), jnp.uint8),
-        pltpu.SemaphoreType.DMA((N_EDGE_SLOTS,)),
-    )
-
-
-def build_decode_call(
-    window: int, d_max: int, n_out_windows: int, interpret: bool
-):
-    """One dispatch decoding a compressed slab to a
-    ``(n_out_windows * window, 2)`` edge slab in HBM (callers trim to the
-    megabatch's ``K * B`` rows)."""
-    kernel = functools.partial(
-        decode_megabatch_kernel,
-        window=window,
-        d_max=d_max,
-        n_out_windows=n_out_windows,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(),
-        in_specs=[
-            pl.BlockSpec((d_max, DESC_COLS), lambda: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-        out_shape=jax.ShapeDtypeStruct((n_out_windows * window, 2), jnp.int32),
-        interpret=interpret,
-    )
-
-
-def build_decode_update_call(
-    n: int, window: int, d_max: int, v_max: int, interpret: bool
-):
-    """One fused dispatch over a compressed megabatch: payload bytes stay in
-    HBM and are span-DMA'd by the kernel; the 3n-int state is seeded into
-    VMEM once and written back once, plus a ``(1,)`` live-edge count."""
-    kernel = functools.partial(
-        edge_stream_decode_update_kernel,
-        v_max=v_max,
-        window=window,
-        d_max=d_max,
-    )
-    state_spec = pl.BlockSpec((n,), lambda: (0,))
-    stats_spec = pl.BlockSpec((1,), lambda: (0,))
-    return pl.pallas_call(
-        kernel,
-        grid=(),
-        in_specs=[
-            pl.BlockSpec((d_max, DESC_COLS), lambda: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            state_spec,
-            state_spec,
-            state_spec,
-        ],
-        out_specs=[state_spec, state_spec, state_spec, stats_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),  # d
-            jax.ShapeDtypeStruct((n,), jnp.int32),  # c
-            jax.ShapeDtypeStruct((n,), jnp.int32),  # v
-            jax.ShapeDtypeStruct((1,), jnp.int32),  # stats: live edges
-        ],
-        interpret=interpret,
     )
 
 
@@ -741,8 +613,8 @@ def build_wavefront_call(
         kernel,
         grid=(),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             stats_spec,
             state_spec,
             state_spec,
@@ -754,39 +626,6 @@ def build_wavefront_call(
             jax.ShapeDtypeStruct((n,), jnp.int32),  # c
             jax.ShapeDtypeStruct((n,), jnp.int32),  # v
             jax.ShapeDtypeStruct((2,), jnp.int32),  # stats
-        ],
-        interpret=interpret,
-    )
-
-
-def build_megabatch_call(
-    n: int, chunk: int, n_chunks: int, v_max: int, interpret: bool
-):
-    """One fused dispatch over a ``(n_chunks, chunk, 2)`` megabatch: edges
-    stay in HBM and are double-buffer DMA'd by the kernel itself; the 3n-int
-    state is seeded into VMEM once and written back once."""
-    kernel = functools.partial(
-        edge_stream_megabatch_kernel,
-        v_max=v_max,
-        n=n,
-        chunk=chunk,
-        n_chunks=n_chunks,
-    )
-    state_spec = pl.BlockSpec((n,), lambda: (0,))
-    return pl.pallas_call(
-        kernel,
-        grid=(),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            state_spec,
-            state_spec,
-            state_spec,
-        ],
-        out_specs=[state_spec, state_spec, state_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),  # d
-            jax.ShapeDtypeStruct((n,), jnp.int32),  # c
-            jax.ShapeDtypeStruct((n,), jnp.int32),  # v
         ],
         interpret=interpret,
     )

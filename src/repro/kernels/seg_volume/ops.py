@@ -3,10 +3,12 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.seg_volume.kernel import build_call
 
 
@@ -19,7 +21,7 @@ def seg_volume(
     k: int,
     block_b: int = 512,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Weighted histogram of ``labels`` (B,) into ``k`` bins via MXU matmul.
 
@@ -33,6 +35,6 @@ def seg_volume(
     wts = jnp.zeros((1, bp), jnp.float32).at[0, :b].set(
         weights.astype(jnp.float32)
     )
-    call = build_call(bp, kp, block_b, block_k, interpret)
+    call = build_call(bp, kp, block_b, block_k, interpret_mode(interpret))
     out = call(lab, wts)
     return out[0, :k]

@@ -2,6 +2,8 @@
 cross-backend equivalence, partial_fit resumability, checkpoint suspend /
 resume, and config validation."""
 
+import json
+
 import numpy as np
 import pytest
 
@@ -90,6 +92,14 @@ def test_config_validation_rejects(bad):
 def test_config_json_roundtrip():
     cfg = ClusterConfig(n=50, backend="multiparam", v_maxes=(4, 8), chunk=32)
     assert ClusterConfig.from_json(cfg.to_json()) == cfg
+
+
+def test_config_json_with_interpret_field_still_restores():
+    # checkpoints written before interpret mode followed the platform
+    cfg = ClusterConfig(n=50, v_max=8, backend="pallas", chunk=32)
+    raw = json.loads(cfg.to_json())
+    raw["interpret"] = True
+    assert ClusterConfig.from_json(json.dumps(raw)) == cfg
 
 
 # ---------------------------------------------------------------------------

@@ -51,14 +51,15 @@ from repro.graph.pipeline import (  # noqa: E402
     D_NROWS,
     D_ROW,
     D_W_I,
+    D_W_J,
     DESC_FIXED,
 )
 from repro.kernels.edge_stream.kernel import (  # noqa: E402
+    DECODE_ROWS,
     DESC_COLS,
-    build_decode_call,
-    build_decode_update_call,
 )
 from repro.kernels.edge_stream.ops import (  # noqa: E402
+    pallas_decode_update_megabatch,
     pallas_update_megabatch,
 )
 
@@ -76,6 +77,26 @@ def _mixed_stream(n, m, seed):
         j[at : at + burst] = rng.integers(0, n, burst)
     j = np.where(j == i, np.minimum(i + 1, n - 1), j)
     return np.stack([i, j], 1).astype(np.int32)
+
+
+def _width_stream(seg, seed):
+    """Four ``seg``-row stretches whose DVE3 blocks take, in turn, u1/u1,
+    u2/u2 (both signs of ``j - i``) and u1/u4 fixed lanes, then the raw
+    fallback (random far endpoints); every id stays below ``WIDE_N``."""
+    rng = np.random.default_rng(seed)
+    steps = rng.integers([0, 64, 0, 0], [40, 200, 40, 40], (seg, 4)).T.ravel()
+    steps[seg : 2 * seg : 256] = 10_000  # a few 3-byte varints: u2 wins
+    i = 40_000 + np.cumsum(steps)
+    off = np.concatenate([
+        rng.integers(1, 60, seg),
+        rng.choice([-1, 1], seg) * rng.integers(200, 30_000, seg),
+        (1 << 20) + rng.integers(0, 1000, seg),
+        rng.integers(0, WIDE_N, seg) - i[3 * seg :],
+    ])
+    return np.stack([i, i + off], 1).astype(np.int32)
+
+
+WIDE_N = 1 << 21
 
 
 def _write(tmp_path, edges, block_edges):
@@ -134,39 +155,37 @@ def test_decode_reference_matches_host_slab(tmp_path):
 # Pallas kernels pinned against the reference (emulator)
 # ---------------------------------------------------------------------------
 
-def test_pallas_decode_kernel_pins_reference(tmp_path):
-    edges = _mixed_stream(400, 4096, 11)
-    path = _write(tmp_path, edges, block_edges=512)
-    for ref, cm in _slab_pairs(path, B=512, K=2):
-        d_max = cm.desc.shape[0]
-        n_out_windows = -(-(cm.out_rows + cm.window) // cm.window)
-        call = build_decode_call(cm.window, d_max, n_out_windows, True)
-        out = np.asarray(
-            call(jnp.asarray(cm.desc), jnp.asarray(cm.payload))
-        )[: cm.out_rows]
-        assert np.array_equal(out, ref)
-
-
-def test_fused_decode_update_kernel_pins_reference(tmp_path):
-    n, v_max = 400, 24
-    edges = _mixed_stream(n, 4096, 13)
-    path = _write(tmp_path, edges, block_edges=512)
+@pytest.mark.parametrize("block_edges", [512, 4096])
+def test_fused_decode_update_kernel_pins_reference(tmp_path, block_edges):
+    """The fused kernel (run through the emulator) equals host decode + the
+    sequential kernel on every lane width and the raw fallback; 4096-row
+    segments span two DECODE_ROWS sub-windows."""
+    n, v_max = WIDE_N, 24
+    edges = _width_stream(block_edges, 13)
+    assert 0 <= edges.min() and edges.max() < n
+    path = _write(tmp_path, edges, block_edges=block_edges)
     seq = ClusterState.init(n)
     fused = ClusterState.init(n)
-    for ref, cm in _slab_pairs(path, B=512, K=2):
+    pairs = _slab_pairs(path, B=512, K=2 * block_edges // 512)
+    segments = {
+        (int(r[D_KIND]), int(r[D_W_I]), int(r[D_W_J]), int(r[D_NROWS]))
+        for _, cm in pairs
+        for r in cm.desc[: cm.n_desc]
+    }
+    assert {(DESC_FIXED, 1, 1), (DESC_FIXED, 2, 2), (DESC_FIXED, 1, 4)} <= {
+        s[:3] for s in segments
+    }
+    assert any(s[0] != DESC_FIXED for s in segments)
+    longest = max(s[3] for s in segments)
+    assert (longest > DECODE_ROWS) == (block_edges > DECODE_ROWS)
+    for ref, cm in pairs:
         seq = pallas_update_megabatch(
             seq, jnp.asarray(ref).reshape(1, cm.out_rows, 2), v_max,
             chunk=512,
         )
-        d_max = cm.desc.shape[0]
-        call = build_decode_update_call(n, cm.window, d_max, v_max, True)
-        d, c, v, stats = call(
-            jnp.asarray(cm.desc), jnp.asarray(cm.payload),
-            fused.d.astype(jnp.int32), fused.c.astype(jnp.int32),
-            fused.v.astype(jnp.int32),
-        )
-        fused = ClusterState(
-            d=d, c=c, v=v, edges_seen=fused.edges_seen + stats[0]
+        fused = pallas_decode_update_megabatch(
+            fused, jnp.asarray(cm.payload), jnp.asarray(cm.desc), v_max,
+            interpret=True,
         )
     _assert_states_equal(seq, fused)
 

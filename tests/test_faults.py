@@ -251,7 +251,7 @@ _CHILD = textwrap.dedent(
     ).plan(src.n_edges)
     cfg = ClusterConfig(
         n=500, v_max=40, backend=backend, chunk=256, batch_edges=1024,
-        autosave_every=2048, autosave_dir=ckpt, interpret=True,
+        autosave_every=2048, autosave_dir=ckpt,
     )
     pre = PreemptionHandler()
     pre.install()
@@ -290,7 +290,6 @@ def _resume_and_compare(ckpt, path, edges, backend):
     out = sc.finalize()
     cfg = ClusterConfig(
         n=500, v_max=40, backend=backend, chunk=256, batch_edges=1024,
-        interpret=True,
     )
     ref = cluster(edges, cfg)
     assert np.array_equal(out.labels, ref.labels)
@@ -585,7 +584,6 @@ def test_transient_chaos_invisible_to_labels(tmp_path, backend):
     )
     cfg = ClusterConfig(
         n=n, v_max=40, backend=backend, chunk=256, batch_edges=2048,
-        interpret=True,
     )
     out = (
         StreamClusterer(cfg)

@@ -6,6 +6,7 @@ import pytest
 
 from repro.kernels.edge_decide.ops import edge_decide
 from repro.kernels.edge_decide.ref import edge_decide_ref
+from repro.kernels.edge_stream.kernel import LANES, SUBLANES, state_rows
 from repro.kernels.edge_stream.ops import edge_stream_cluster
 from repro.kernels.edge_stream.ref import edge_stream_ref
 from repro.kernels.seg_volume.ops import seg_volume
@@ -95,3 +96,10 @@ def test_seg_volume_block_shape_sweep(block_b, block_k):
     np.testing.assert_allclose(
         np.asarray(out_k), np.asarray(out_r), rtol=1e-5, atol=1e-5
     )
+
+
+@pytest.mark.parametrize("n", [1, 1023, 1024, 1025, 3_997_962])
+def test_state_rows_cover_n_in_whole_tiles(n):
+    rows = state_rows(n)
+    assert rows % SUBLANES == 0
+    assert rows * LANES >= n > (rows - SUBLANES) * LANES
