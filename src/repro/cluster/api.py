@@ -47,6 +47,7 @@ from repro.graph.errors import RetryPolicy
 from repro.graph.pipeline import D_KIND, DESC_RAW, BatchPipeline
 from repro.graph.wavefront import plan_waves
 from repro.graph.sources import ArraySource, EdgeSource, as_source
+from repro.obs import span, spanned
 
 _CONFIG_FILE = "cluster_config.json"
 
@@ -181,7 +182,8 @@ class Clustering:
         """Canonical labels 0..K-1 by first appearance (cross-backend
         comparable)."""
         if self._labels is None:
-            self._labels = canonical_labels(np.asarray(self.raw_labels))
+            with span("repro.labels"):
+                self._labels = canonical_labels(np.asarray(self.raw_labels))
         return self._labels
 
     @property
@@ -353,7 +355,8 @@ class StreamClusterer:
                 "partial_fit; use cluster() for one-shot runs"
             )
         if state is None:
-            state = self._backend.init_fn(config)
+            with span("repro.clusterer.init"):
+                state = self._backend.init_fn(config)
         _check_state(state, config, self._backend)
         self._state = state
         self._last_result = None
@@ -439,7 +442,8 @@ class StreamClusterer:
         (there is no source to mint one); :meth:`fit` refreshes the token
         from its source after every batch.
         """
-        result = self._backend.fn(edge_batch, self.config, self._state)
+        with span("repro.dispatch"):
+            result = self._backend.fn(edge_batch, self.config, self._state)
         self._state = result.state
         self._last_result = result
         if self._refine is not None:
@@ -490,7 +494,8 @@ class StreamClusterer:
                     self.config.wavefront,
                     gap=self.config.wavefront_gap,
                 )
-            result = self._backend.wavefront_fn(plan, self.config, self._state)
+            with span("repro.dispatch"):
+                result = self._backend.wavefront_fn(plan, self.config, self._state)
             stats = result.info.pop("wavefront_stats", None)
             if stats is not None:
                 # lazy device add — host sync deferred to finalize()
@@ -507,9 +512,10 @@ class StreamClusterer:
             self.wavefront_plan_seconds += plan.plan_seconds
             self.wavefront_widths.append(int(plan.width))
         else:
-            result = self._backend.megabatch_fn(
-                edge_batches, self.config, self._state
-            )
+            with span("repro.dispatch"):
+                result = self._backend.megabatch_fn(
+                    edge_batches, self.config, self._state
+                )
         self._state = result.state
         self._last_result = result
         if self._refine is not None:
@@ -541,9 +547,10 @@ class StreamClusterer:
                 f"backend {self.config.backend!r} has no device decode "
                 "path; use partial_fit_megabatch with host-decoded edges"
             )
-        result = self._backend.decode_fn(
-            cmega.validate(), self.config, self._state
-        )
+        with span("repro.dispatch"):
+            result = self._backend.decode_fn(
+                cmega.validate(), self.config, self._state
+            )
         self._state = result.state
         self._last_result = result
         self._cursor = Cursor(self._cursor.row + int(cmega.n_rows))
@@ -570,6 +577,7 @@ class StreamClusterer:
         self._last_autosave_row = self._cursor.row
         self.autosaves += 1
 
+    @spanned("repro.fit")
     def fit(
         self,
         edges,
@@ -755,6 +763,7 @@ class StreamClusterer:
             self._autosave(config)
         return self
 
+    @spanned("repro.finalize")
     def finalize(self) -> Clustering:
         """The clustering of everything ingested so far.  Does not consume
         the state — more ``partial_fit`` calls may follow.
@@ -842,11 +851,11 @@ class StreamClusterer:
         # result.state/labels may alias via to_device() — is consumed by the
         # next partial_fit.  Snapshot the result to host so a finalized
         # Clustering outlives further ingestion, per this method's contract.
+        with span("repro.finalize.fetch"):
+            state = None if result.state is None else result.state.to_numpy()
+            raw_labels = np.asarray(result.labels)
         return Clustering(
-            state=None if result.state is None else result.state.to_numpy(),
-            config=self.config,
-            raw_labels=np.asarray(result.labels),
-            info=info,
+            state=state, config=self.config, raw_labels=raw_labels, info=info
         )
 
     # ------------------------------------------------------------------

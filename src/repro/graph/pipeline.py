@@ -46,12 +46,15 @@ import numpy as np
 
 from repro.graph.codecs import Cursor, as_cursor
 from repro.graph.errors import RetryPolicy, StallError, retrying_slices
+from repro.obs import span
 
 # Sentinel node id used to pad edge batches/chunks to fixed shapes; padded
 # edges are no-ops in every clustering tier.  (Canonical definition — re-
 # exported by ``repro.core.streaming`` and ``repro.graph.stream`` for
 # backwards compatibility.)
 PAD = -1
+
+_END = object()  # the end of an iterator, as ``next``'s default
 
 
 # ---------------------------------------------------------------------------
@@ -447,9 +450,13 @@ class BatchPipeline:
                 self._count_retry,
             )
         else:
-            src_iter = self.source.resume(start)
+            src_iter = iter(self.source.resume(start))
         try:
-            for sl in src_iter:
+            while True:
+                with span("repro.source.read"):
+                    sl = next(src_iter, _END)
+                if sl is _END:
+                    return
                 sl = np.asarray(sl)
                 held.append((int(sl.nbytes), int(sl.shape[0])))
                 held_rows += int(sl.shape[0])
@@ -873,9 +880,17 @@ def _prefetch_iter(
     path, where there is no worker to watch.
     """
     if depth <= 0:
-        yield from gen
-        return
-    ex = ThreadPoolExecutor(max_workers=1)
+        try:
+            while True:
+                with span("repro.pipeline.stage"):
+                    item = next(gen, _END)
+                if item is _END:
+                    return
+                yield item
+        finally:
+            gen.close()
+    # the thread's name shows in thread dumps (``threading.enumerate``)
+    ex = ThreadPoolExecutor(max_workers=1, thread_name_prefix="repro-prefetch")
     pulls = 0
 
     def pull():
@@ -888,7 +903,8 @@ def _prefetch_iter(
             heartbeat.step_start()
         try:
             try:
-                out = ("item", next(gen))
+                with span("repro.pipeline.stage"):
+                    out = ("item", next(gen))
             except StopIteration:
                 out = ("stop", None)
             except BaseException as e:  # propagated on consumer after join
@@ -906,7 +922,8 @@ def _prefetch_iter(
             futures.append(ex.submit(pull))
         while futures:
             try:
-                kind, value = futures[0].result(timeout=stall_timeout)
+                with span("repro.pipeline.wait"):
+                    kind, value = futures[0].result(timeout=stall_timeout)
             except _FuturesTimeout:
                 stalled = True
                 raise StallError(
