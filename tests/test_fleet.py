@@ -238,6 +238,54 @@ def test_fleet_restore_rejects_single_stream_checkpoint(tmp_path):
         FleetClusterer.restore(d)
 
 
+def test_fleet_chunked_matches_single_streams_across_move_paths():
+    # the tenants' chunks take different move paths under one vmap:
+    # tenant 0's fits one round of MOVE_SLOTS winners, tenant 1's (a fresh
+    # state, every row wins) the chunk-wide pass, tenant 2's two rounds
+    import jax.numpy as jnp
+
+    from repro.core.chunked import MOVE_SLOTS, chunked_update
+    from repro.core.state import ClusterState
+    from test_chunked_moves import (
+        CHUNK,
+        V_MAX,
+        case_exactly_slots,
+        case_fresh,
+        case_slots_plus_one,
+        reference,
+    )
+
+    rng = np.random.default_rng(11)
+    cases = [case_exactly_slots(rng), case_fresh(rng), case_slots_plus_one(rng)]
+    winners = [reference(*state, e, V_MAX)[-1][0] for state, e, _ in cases]
+    assert winners[0] <= MOVE_SLOTS < winners[2] <= CHUNK // 2 < winners[1], winners
+
+    def as_device(d, c, v, edges_seen):
+        return dict(
+            d=jnp.asarray(d, jnp.int32),
+            c=jnp.asarray(c, jnp.int32),
+            v=jnp.asarray(v, jnp.int32),
+            edges_seen=jnp.asarray(edges_seen, jnp.int32),
+        )
+
+    fleet = FleetState(
+        **as_device(*(np.stack([s[k] for s, _, _ in cases]) for k in range(3)), [0] * 3)
+    )
+    edges = jnp.asarray(np.stack([e for _, e, _ in cases]), jnp.int32)
+    got = fleet_update_chunked(fleet, edges, jnp.int32(V_MAX), chunk=CHUNK).to_numpy()
+    for t, (state, e, _) in enumerate(cases):
+        single = chunked_update(
+            ClusterState(**as_device(*state, 0)),
+            jnp.asarray(e, jnp.int32),
+            jnp.int32(V_MAX),
+            chunk=CHUNK,
+        )
+        for leaf in ("d", "c", "v", "edges_seen"):
+            assert np.array_equal(
+                np.asarray(getattr(got, leaf))[t], np.asarray(getattr(single, leaf))
+            ), (t, leaf)
+
+
 # ---------------------------------------------------------------------------
 # Ragged fleets: idle tenants are bit-untouched
 # ---------------------------------------------------------------------------

@@ -9,12 +9,16 @@ mode) shows up here.  The persistent compile cache is off around these
 compiles: such a cache entry could not be read back without a chip.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.chunked import chunked_update_megabatch
+from repro.core.chunked import chunked_update, chunked_update_megabatch
+from repro.core.fleet import fleet_update_chunked
 from repro.core.state import ClusterState, FleetState
 from repro.graph.pipeline import DESC_COLS
 from repro.kernels.edge_decide.ops import edge_decide
@@ -29,6 +33,7 @@ from repro.kernels.edge_stream.ops import (
 from repro.kernels.seg_volume.ops import seg_volume
 
 LIVEJOURNAL_N = 3_997_962
+GRAPH500_N = 1 << 22  # the chunked benchmark cell: SCALE 22, 2^20-row batches
 EXACT_N = 1 << 20
 V_MAX = 64
 
@@ -68,6 +73,62 @@ def _state(sharding, n):
 
 def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _fills(hlo, elems):
+    """Values broadcast into an int32 array of ``elems`` elements."""
+    consts = dict(
+        re.findall(r"%(\S+) = s32\[\](?:\{[^}]*\})? constant\((-?\d+)\)", hlo)
+    )
+    return [
+        int(consts[op])
+        for shape, op in re.findall(
+            r"= s32\[([\d,]+)\]\{[^}]*\} broadcast\(%([^)\s]+)\)", hlo
+        )
+        if math.prod(int(x) for x in shape.split(",")) == elems
+    ]
+
+
+def test_chunked_update_fills_nothing_state_sized_and_keeps_state_in_vmem(one_chip):
+    # the chunk's winners come from a compare over the chunk alone: no
+    # (n + 1)-sized array is filled per chunk
+    compiled = chunked_update.lower(
+        _state(one_chip, GRAPH500_N),
+        _sds(one_chip, (1 << 20, 2)),
+        _sds(one_chip, ()),
+        chunk=1024,
+    ).compile()
+    hlo = compiled.as_text()
+    assert _fills(hlo, GRAPH500_N + 1) == []
+    # and the state stays in VMEM (memory space 1) across the scan, which a
+    # cond in the chunk step would undo
+    assert f"s32[{GRAPH500_N + 1}]{{0:T(1024)S(1)}}" in hlo
+
+
+@pytest.fixture(scope="module")
+def fleet_two(one_chip):
+    T = 2
+    leaf = _sds(one_chip, (T, GRAPH500_N))
+    state = FleetState(d=leaf, c=leaf, v=leaf, edges_seen=_sds(one_chip, (T,)))
+    return T, fleet_update_chunked.lower(
+        state, _sds(one_chip, (T, 1 << 20, 2)), _sds(one_chip, ()), chunk=1024
+    ).compile()
+
+
+def test_fleet_chunked_has_no_winner_fill(fleet_two):
+    # under vmap the scatters keep their own zero fills; the per-chunk
+    # winner array (filled with the chunk size) is gone
+    T, compiled = fleet_two
+    assert 1024 not in _fills(compiled.as_text(), T * (GRAPH500_N + 1))
+
+
+def test_fleet_chunked_move_loops_add_no_state_copies(fleet_two):
+    # under vmap the move loops select over the whole (T, n + 1) state, but
+    # in place: the temporaries stay at about five state-sized arrays, as
+    # with the parent's chunk-wide moves and winner fill
+    T, compiled = fleet_two
+    state_bytes = T * (GRAPH500_N + 1) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * state_bytes
 
 
 def test_chunked_megabatch_compiles_at_livejournal_n(one_chip):
