@@ -34,6 +34,7 @@ from repro.kernels.seg_volume.ops import seg_volume
 
 LIVEJOURNAL_N = 3_997_962
 GRAPH500_N = 1 << 22  # the chunked benchmark cell: SCALE 22, 2^20-row batches
+PAPER_SCALE_N = 1 << 26  # Graph500 SCALE 26: 805 MB of state, past VMEM
 EXACT_N = 1 << 20
 V_MAX = 64
 
@@ -103,6 +104,25 @@ def test_chunked_update_fills_nothing_state_sized_and_keeps_state_in_vmem(one_ch
     # and the state stays in VMEM (memory space 1) across the scan, which a
     # cond in the chunk step would undo
     assert f"s32[{GRAPH500_N + 1}]{{0:T(1024)S(1)}}" in hlo
+
+
+def test_chunked_update_at_paper_scale_keeps_one_state_in_hbm(one_chip):
+    # the state is past VMEM, so every scatter of the scan runs against HBM:
+    # in place, with no state-sized fill or copy; the only temporaries are
+    # the sink slot's concatenation of the three n + 1 arrays
+    n = PAPER_SCALE_N
+    compiled = chunked_update.lower(
+        _state(one_chip, n),
+        _sds(one_chip, (1 << 20, 2)),
+        _sds(one_chip, ()),
+        chunk=1024,
+    ).compile()
+    hlo = compiled.as_text()
+    assert f"s32[{n + 1}]{{0:T(1024)S(1)}}" not in hlo
+    assert _fills(hlo, n + 1) == []
+    assert not re.search(rf"= s32\[{n + 1}\]\{{[^}}]*\}} copy\(", hlo)
+    state_bytes = 3 * (n + 1) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * state_bytes
 
 
 @pytest.fixture(scope="module")
