@@ -32,7 +32,8 @@ pseudocode, which is what the reference C++ implementation does.)
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +41,7 @@ import numpy as np
 
 from repro.core.state import ClusterState, count_live_edges
 from repro.graph.pipeline import PAD
+from repro.obs import span
 
 Array = jax.Array
 
@@ -267,14 +269,78 @@ def cluster_stream_scan(edges: Array, v_max: int, n: int):
 def canonical_labels(c: np.ndarray) -> np.ndarray:
     """Map community labels to 0..K-1 by first appearance (for comparisons).
 
-    Fully vectorised: ``np.unique`` gives each label's first-occurrence index;
-    ranking those indices by argsort yields the first-appearance order without
-    any per-element Python work (this sits on every quality comparison, where
-    the old dict loop was O(n) interpreter time).
+    Labels in a dense range -- integers in ``0..hi`` with ``hi < 2 * c.size``,
+    as every on-device tier's node ids are -- take an O(n) pass with no sort:
+    a reversed scatter of positions gives each label some position of its own
+    (the first, where NumPy's last write wins), and a check that no element
+    lies before its label's position proves it the first one.  Each element's
+    rank is then the count of first positions up to its label's.  NumPy does
+    not promise which of repeated writes wins, so where the check fails, and
+    for any other labels (negative, ``2 * c.size`` or more, not integers, or
+    none), ``np.unique`` sorts them inside the ``repro.labels.sort`` span.
+    Both paths give the same ``intp`` labels.  The scatter is one sequential
+    pass; the gathers, the check and the counts run over blocks of
+    ``_BLOCK`` elements on up to ``_THREADS`` threads, since NumPy releases
+    the GIL inside each.
     """
     c = np.asarray(c)
-    _, first, inv = np.unique(c, return_index=True, return_inverse=True)
-    order = np.argsort(first, kind="stable")
-    rank = np.empty_like(order)
-    rank[order] = np.arange(order.shape[0])
-    return rank[inv]
+    if c.size and np.issubdtype(c.dtype, np.integer):
+        lo, hi = int(c.min()), int(c.max())
+        if lo >= 0 and hi + 1 <= 2 * c.size:
+            idx = c.reshape(-1)
+            if not np.can_cast(idx.dtype, np.intp):  # np.take refuses uint64
+                idx = idx.astype(np.intp)
+            rank = _first_appearance_ranks(idx, _first_positions(idx, hi + 1))
+            if rank is not None:
+                return rank.reshape(c.shape)
+    with span("repro.labels.sort"):
+        _, first, inv = np.unique(c, return_index=True, return_inverse=True)
+        order = np.argsort(first, kind="stable")
+        rank = np.empty_like(order)
+        rank[order] = np.arange(order.shape[0])
+        return rank[inv]
+
+
+_BLOCK = 1 << 20  # elements per task of canonical_labels' threaded passes
+_THREADS = 8  # the passes are bound by memory: a few threads take the gain
+
+
+def _first_positions(idx: np.ndarray, labels: int) -> np.ndarray:
+    """``first[k]``: a position of label ``k`` in ``idx``, for each ``k`` in
+    ``idx``; the other entries are never written.  Written last to first, so
+    the first occurrence is the last write."""
+    pos = np.arange(idx.size, dtype=np.int32 if idx.size < 2**31 else np.int64)
+    first = np.empty(labels, pos.dtype)
+    first[idx[::-1]] = pos[::-1]
+    return first
+
+
+def _first_appearance_ranks(
+    idx: np.ndarray, first: np.ndarray
+) -> Optional[np.ndarray]:
+    """Each element's label ranked by first appearance, or ``None`` where
+    some ``first[k]`` is not the first position of ``k`` in ``idx``."""
+    n = idx.size
+    at = np.empty(n, first.dtype)  # the position ``first`` gives each label
+    rank = np.empty(n, np.intp)  # first positions up to each position
+    blocks = [slice(s, min(s + _BLOCK, n)) for s in range(0, n, _BLOCK)]
+
+    def count_firsts(b: slice) -> int:
+        np.take(first, idx[b], out=at[b])
+        pos = np.arange(b.start, b.stop, dtype=at.dtype)
+        # each first[k] is a position of k: no later than any of them, it is
+        # the first
+        if not (at[b] <= pos).all():
+            return -1
+        np.cumsum(at[b] == pos, out=rank[b])
+        return int(rank[b.stop - 1])
+
+    with ThreadPoolExecutor(min(len(blocks), _THREADS)) as pool:
+        counts = list(pool.map(count_firsts, blocks))
+        if min(counts) < 0:
+            return None
+        before = np.cumsum([-1] + counts[:-1])  # less one: ranks start at 0
+        list(pool.map(lambda b, k: np.add(rank[b], k, out=rank[b]), blocks, before))
+        out = np.empty(n, np.intp)
+        list(pool.map(lambda b: np.take(rank, at[b], out=out[b]), blocks))
+    return out

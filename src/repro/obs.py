@@ -20,6 +20,8 @@ span                       thread    contains
 ``repro.finalize``         caller    all of ``StreamClusterer.finalize``
 ``repro.finalize.fetch``   caller    the state and labels copied to the host
 ``repro.labels``           caller    ``canonical_labels`` in ``.labels``
+``repro.labels.sort``      caller    ``canonical_labels``' sort, for labels
+                                     outside a dense range (``core/streaming``)
 =========================  ========  =========================================
 
 With ``prefetch=0`` there is no prefetch thread: ``repro.pipeline.stage``

@@ -2,7 +2,10 @@
 cross-backend equivalence, partial_fit resumability, checkpoint suspend /
 resume, and config validation."""
 
+import contextlib
 import json
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -19,6 +22,10 @@ from repro.cluster import (
     modularity,
 )
 from repro.graph.generators import sbm_stream
+
+ROOT = str(Path(__file__).resolve().parents[1])
+if ROOT not in sys.path:  # the benchmark's plain reference, ``chipbench``
+    sys.path.insert(0, ROOT)
 
 ALL_BACKENDS = (
     "chunked", "dense", "distributed", "multiparam", "oracle", "pallas", "scan",
@@ -380,3 +387,31 @@ def test_state_init_shapes():
     assert s.n == 17
     assert s.d.shape == s.c.shape == s.v.shape == (17,)
     assert int(s.edges_seen) == 0
+
+
+@pytest.mark.parametrize("fleet", [False, True])
+def test_labels_equal_reference_canonical_of_raw_labels(fleet, monkeypatch):
+    """``.labels`` (a first-appearance pass over node ids) equal the
+    benchmark reference's ``canonical`` (a sort) of the raw labels, for a
+    ``chunked`` stream and for each tenant of a fleet, and none of them
+    takes the sort path."""
+    from chipbench.reference import canonical
+    from repro.cluster import cluster_fleet
+    from repro.core import streaming
+
+    sorts = []
+    monkeypatch.setattr(
+        streaming, "span", lambda name: sorts.append(name) or contextlib.nullcontext()
+    )
+    cfg = _cfg("chunked", n=256, batch_edges=128)
+    if fleet:
+        streams = [_random_stream(256, 3000, 1), _random_stream(256, 700, 2)]
+        got = cluster_fleet(streams, cfg)
+        rows = list(zip(got.labels, got.raw_labels))
+    else:
+        got = cluster(_random_stream(256, 3000, 1), cfg)
+        rows = [(got.labels, got.raw_labels)]
+    for labels, raw in rows:
+        assert np.array_equal(labels, canonical(np.asarray(raw)))
+        assert 1 < len(np.unique(labels)) < 256  # some nodes moved, not all
+    assert sorts == []

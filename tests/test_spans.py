@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData, trace
 
-from repro.cluster import ClusterConfig, StreamClusterer
+from repro.cluster import ClusterConfig, Clustering, StreamClusterer
 from repro.graph.sources import BinaryFileSource
 
 N, M, BATCH, SLICE = 4096, 20_000, 2048, 3000
@@ -92,8 +92,20 @@ def test_fit_spans(edge_file, tmp_path, megabatch_k):
         assert len(named(name, caller)) == count(name) == 1, name
     (fetch,) = named("repro.finalize.fetch", caller)
     assert _inside(fetch, named("repro.finalize", caller))
+    assert count("repro.labels.sort") == 0  # node ids take the dense path
     for d in named("repro.dispatch", caller):
         assert _inside(d, named("repro.fit", caller))
 
     _, untraced = _run(edge_file, megabatch_k)
     np.testing.assert_array_equal(labels, untraced)
+
+
+def test_sort_span_nests_in_labels(tmp_path):
+    """Labels outside a dense range are sorted, inside ``repro.labels``."""
+    result = Clustering(state=None, config=None, raw_labels=np.array([9, -1, 9, 4]))
+    with trace(str(tmp_path)):
+        labels = result.labels
+    np.testing.assert_array_equal(labels, [0, 1, 0, 2])
+    (line,) = [spans for spans in _host_lines(tmp_path)]
+    (sort,) = [sp for sp in line if sp[0] == "repro.labels.sort"]
+    assert _inside(sort, [sp for sp in line if sp[0] == "repro.labels"])

@@ -1,10 +1,13 @@
 """Tier-equivalence and behaviour tests for the paper's Algorithm 1."""
 
+import contextlib
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis_compat import given, settings, st
 
+from repro.core import streaming
 from repro.core.chunked import cluster_stream_chunked
 from repro.core.metrics import avg_f1, modularity, nmi
 from repro.core.streaming import (
@@ -176,13 +179,99 @@ def _canonical_labels_loop(c):
 def test_canonical_labels_matches_loop_reference(seed, lo, hi):
     rng = np.random.default_rng(seed)
     x = rng.integers(lo, hi, size=rng.integers(1, 400))
-    got = canonical_labels(x)
-    want = _canonical_labels_loop(x)
+    # and labels in 0..min(hi, size) - 1, which take the dense path
+    dense = rng.integers(0, min(hi, x.size), size=x.size)
+    for labels in (x, dense):
+        got = canonical_labels(labels)
+        want = _canonical_labels_loop(labels)
+        assert np.array_equal(got, want)
+        # canonical form: labels are 0..K-1, first appearances are increasing
+        assert got.min() == 0 and got.max() == len(np.unique(labels)) - 1
+        first_pos = [np.argmax(got == k) for k in range(got.max() + 1)]
+        assert first_pos == sorted(first_pos)
+
+
+def _spans_entered(monkeypatch):
+    """Record the names of the spans ``repro.core.streaming`` enters."""
+    names = []
+
+    def span(name):
+        names.append(name)
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(streaming, "span", span)
+    return names
+
+
+_SPARSE = np.array([20, 3, 20, 17, 3, 0, 23, 20, 17, 9, 3, 1])
+
+
+@pytest.mark.parametrize(
+    "labels, sorts",
+    [
+        (np.arange(50), False),  # every node on its own label
+        (np.full(50, 7), False),  # all one label
+        (np.arange(50)[::-1], False),
+        (np.array([0, 49, 49, 3, 0] + [49] * 45), False),  # uses n - 1
+        (_SPARSE, False),  # 7 labels spread over 0..2n - 1
+        (_SPARSE.astype(np.int32), False),
+        (_SPARSE.astype(np.int64), False),
+        (_SPARSE.astype(np.uint64), False),  # np.take refuses uint64 indices
+        (np.array([0]), False),
+        (np.array([5]), True),  # hi + 1 > 2n
+        (np.array([], dtype=np.int64), True),
+        (np.array([], dtype=np.int32), True),
+        (np.array([0, 1, 2, 11, 2, 1]), False),  # hi + 1 = 2n
+        (np.array([0, 1, 2, 12, 2, 1]), True),  # hi + 1 > 2n
+        (np.array([3, -1, 3, 0]), True),  # a negative label
+    ],
+)
+@pytest.mark.parametrize("block", [streaming._BLOCK, 3])
+def test_canonical_labels_dense_path(labels, sorts, block, monkeypatch):
+    monkeypatch.setattr(streaming, "_BLOCK", block)  # 3: many blocks, threads
+    spans = _spans_entered(monkeypatch)
+    got = canonical_labels(labels)
+    want = _canonical_labels_loop(labels)
     assert np.array_equal(got, want)
-    # canonical form: labels are 0..K-1, first appearances are increasing
-    assert got.min() == 0 and got.max() == len(np.unique(x)) - 1
-    first_pos = [np.argmax(got == k) for k in range(got.max() + 1)]
-    assert first_pos == sorted(first_pos)
+    assert got.shape == labels.shape and got.dtype == np.intp
+    assert spans == (["repro.labels.sort"] if sorts else [])
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_canonical_labels_many_blocks(seed, monkeypatch):
+    """Hundreds of blocks on the threads, a few labels shared across all of
+    them: the same labels as the loop, and no sort."""
+    monkeypatch.setattr(streaming, "_BLOCK", 97)
+    spans = _spans_entered(monkeypatch)
+    rng = np.random.default_rng(seed)
+    n = 20_000
+    labels = np.arange(n, dtype=np.int32)
+    moved = rng.random(n) < 0.5
+    labels[moved] = rng.integers(0, 50, moved.sum()) * 397  # founders
+    assert np.array_equal(canonical_labels(labels), _canonical_labels_loop(labels))
+    assert spans == []
+
+
+@pytest.mark.parametrize("block", [streaming._BLOCK, 2])
+def test_canonical_labels_wrong_first_position_takes_the_sort_path(
+    block, monkeypatch
+):
+    monkeypatch.setattr(streaming, "_BLOCK", block)
+    idx = np.array([4, 2, 4, 0, 2, 4], dtype=np.intp)
+    first = streaming._first_positions(idx, 5)
+    assert list(first[[4, 2, 0]]) == [0, 1, 3]
+    assert np.array_equal(
+        streaming._first_appearance_ranks(idx, first), _canonical_labels_loop(idx)
+    )
+    later = first.copy()
+    later[4] = 5  # a later occurrence of label 4
+    assert streaming._first_appearance_ranks(idx, later) is None
+    # canonical_labels handed such an array sorts, and is still right
+    monkeypatch.setattr(streaming, "_first_positions", lambda i, k: later)
+    spans = _spans_entered(monkeypatch)
+    got = canonical_labels(idx)
+    assert np.array_equal(got, _canonical_labels_loop(idx))
+    assert spans == ["repro.labels.sort"]
 
 
 def test_canonical_labels_examples():
